@@ -20,7 +20,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.distributed.grad_compress import make_compressed_allreduce
 from repro.launch.hlo_analysis import HloAnalysis
-mesh = jax.make_mesh((8,), ("data",))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((8,), ("data",))
 n = 1 << 22
 xs = jax.ShapeDtypeStruct((8, n), jnp.float32)
 sh = NamedSharding(mesh, P("data", None))
@@ -40,7 +41,9 @@ with mesh:
 def run() -> list:
     lines = []
     repo = Path(__file__).resolve().parents[1]
-    env = dict(os.environ)
+    # a CPU virtual-device rehearsal: pinned to the CPU backend so the child
+    # never reaches for an accelerator the parent may hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = str(repo / "src")
     r = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,

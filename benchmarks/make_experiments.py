@@ -81,7 +81,7 @@ def main():
     w = out.append
     w("# EXPERIMENTS — HP-MDR on TPU\n\n")
     w("Hardware model: TPU v5e-class — 197 TFLOP/s bf16, 819 GB/s HBM, "
-      "~50 GB/s/link ICI.  All numbers are derived from compiled dry-run "
+      "200 GB/s (1,600 Gbit/s) ICI.  All numbers are derived from compiled dry-run "
       "artifacts (no TPU in this container); see DESIGN.md for the "
       "methodology and `repro/launch/hlo_analysis.py` for the loop-aware "
       "HLO cost model (XLA's cost_analysis counts while-loop bodies once; "

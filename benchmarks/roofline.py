@@ -9,9 +9,9 @@ bytes / collective wire bytes from the optimized HLO
   memory term     = hbm_bytes / hbm_bw
   collective term = wire_bytes / link_bw
 
-The peaks are imported from ``repro.tune.cost`` (single source of truth:
-this artifact and the tuner's cost model can never disagree; the TPU row is
-the v5e-class 197 TFLOP/s / 819 GB/s / 50 GB/s-link chip).  Each cell also
+The peaks come from ``repro.tune.cost.PEAKS``, keyed by ``device_kind``
+(single source of truth: this artifact and the tuner's cost model can never
+disagree; the v5e row is 197 TFLOP/s / 819 GB/s / 200 GB/s link).  Each cell also
 runs one measured probe write, so the artifact records the model's
 calibration quality (``model_fraction`` = calibrated prediction / measured)
 — the honesty check behind ``docs/autotune.md``'s cost-model section.
@@ -21,18 +21,13 @@ budget-gated by ``benchmarks/check_regressions.py``).
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, List
 
 from benchmarks.common import row, write_json
 from repro.tune import cost as tc
 from repro.tune import search as ts
 from repro.tune.config import DEFAULT_CONFIG
-
-# re-exported for backward compatibility: these used to live here; the
-# canonical definitions moved into the tuner's cost model
-PEAK_FLOPS = tc.PEAK_FLOPS
-HBM_BW = tc.HBM_BW
-LINK_BW = tc.LINK_BW
 
 SHAPES = [(1 << 14,), (1 << 16,)]
 LEVELS = 3
@@ -41,7 +36,7 @@ LEVELS = 3
 def roofline_cells(shapes=SHAPES, levels: int = LEVELS) -> List[Dict]:
     """One cell per (shape x bitplane design): HLO-derived roofline terms
     plus a measured probe of the same program."""
-    peaks = tc.platform_peaks()
+    peaks = tc.device_peaks()
     cells: List[Dict] = []
     for shape in shapes:
         model = tc.CostModel(shape, levels)
@@ -78,9 +73,10 @@ def run() -> List[str]:
     import jax
 
     cells = roofline_cells()
-    peaks = tc.platform_peaks()
+    kind = jax.devices()[0].device_kind
+    peaks = tc.device_peaks(kind)
     # calibrated section: nominal peaks / fitted scale = the rates this
-    # machine actually sustained on the probes.  ``tune.cost.platform_peaks``
+    # machine actually sustained on the probes.  ``tune.cost.device_peaks``
     # reads it back on later runs, so the tuner's cost model starts from the
     # machine, not the spec sheet.  (Fixed point: effective = cost/measured
     # regardless of which peaks scored the probes, so re-running against an
@@ -91,10 +87,9 @@ def run() -> List[str]:
     result = {
         "peaks": {"flops": peaks.flops, "hbm_bw": peaks.hbm_bw,
                   "link_bw": peaks.link_bw},
-        "nominal_tpu": {"flops": PEAK_FLOPS, "hbm_bw": HBM_BW,
-                        "link_bw": LINK_BW},
+        "nominal_v5e": dataclasses.asdict(tc.PEAKS[tc.V5E]),
         "calibrated": {
-            "platform": jax.default_backend(), "scale": scale,
+            "device_kind": kind, "scale": scale,
             "flops": peaks.flops / scale, "hbm_bw": peaks.hbm_bw / scale,
             "link_bw": peaks.link_bw / scale,
         },

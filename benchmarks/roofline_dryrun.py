@@ -26,7 +26,10 @@ import json
 from pathlib import Path
 from typing import Dict, List
 
-from repro.tune.cost import HBM_BW, LINK_BW, PEAK_FLOPS
+from repro.tune.cost import PEAKS, V5E
+
+PEAK_FLOPS, HBM_BW, LINK_BW = (PEAKS[V5E].flops, PEAKS[V5E].hbm_bw,
+                                PEAKS[V5E].link_bw)
 
 OUT_DIR = Path(__file__).resolve().parents[1] / "out" / "dryrun"
 

@@ -1,5 +1,5 @@
 # One function per paper table. Print ``name,us_per_call,derived`` CSV.
-"""Benchmark driver: one module per paper table/figure (see DESIGN.md §9).
+"""Benchmark driver: one module per paper table/figure.
 
   Fig 6/7   bitplane_designs        Fig 8    lossless_strategies
   Fig 9     pipeline_overlap        Fig 10   weak_scaling
@@ -26,6 +26,7 @@ import inspect
 import sys
 import traceback
 
+from repro import compile_cache
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
@@ -51,6 +52,7 @@ def main() -> None:
     ap.add_argument("--devices", type=int, default=None,
                     help="device count forwarded to sharding-aware benchmarks")
     args = ap.parse_args()
+    compile_cache.enable()
     mods = [args.only] if args.only else MODULES
     print("name,us_per_call,derived")
     failures = 0

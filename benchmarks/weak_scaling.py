@@ -118,7 +118,9 @@ print("RESULT " + json.dumps({{
 
 
 def _one(n_dev: int, repo: Path) -> Optional[dict]:
-    env = dict(os.environ)
+    # a CPU virtual-device rehearsal: pinned to the CPU backend so the child
+    # never reaches for an accelerator the parent may hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
     env["PYTHONPATH"] = str(repo / "src")
     r = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,

@@ -14,6 +14,7 @@ import numpy as np
 from repro.core import qoi as qq
 from repro.data.fields import velocity_field
 from repro.store import DatasetStore, DatasetWriter, RetrievalService
+from repro import compile_cache
 
 
 def main():
@@ -57,4 +58,5 @@ def _run(vs, truth, root):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

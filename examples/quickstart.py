@@ -7,6 +7,7 @@ import numpy as np
 from repro.core import refactor as rf
 from repro.core import retrieve as rt
 from repro.data.fields import gaussian_field
+from repro import compile_cache
 
 
 def main():
@@ -30,4 +31,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

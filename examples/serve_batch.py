@@ -9,6 +9,7 @@ import argparse
 
 from repro.configs.base import list_archs, smoke_config
 from repro.launch.driver import serve_greedy
+from repro import compile_cache
 
 
 def main():
@@ -33,4 +34,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

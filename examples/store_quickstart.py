@@ -13,6 +13,7 @@ import numpy as np
 
 from repro.data.fields import gaussian_field
 from repro.store import DatasetStore, DatasetWriter, RetrievalService
+from repro import compile_cache
 
 
 def main():
@@ -53,4 +54,5 @@ def _run(root):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

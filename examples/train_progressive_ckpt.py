@@ -20,6 +20,7 @@ from repro.ckpt import manager as ckpt_mgr
 from repro.models.model import Model, count_params
 from repro.optim import adamw
 from repro.train.loop import Trainer, TrainerConfig, synthetic_data
+from repro import compile_cache
 
 
 def main():
@@ -77,4 +78,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

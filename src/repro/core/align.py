@@ -59,7 +59,6 @@ def align_encode(
     return mag, sign, e
 
 
-@functools.partial(jax.jit, static_argnames=("mag_bits", "planes_kept"))
 def align_decode(
     mag: jax.Array,
     sign: jax.Array,
@@ -69,15 +68,23 @@ def align_decode(
 ) -> jax.Array:
     """Inverse of align_encode. If ``planes_kept`` < mag_bits, the magnitude is
     assumed already truncated to its top ``planes_kept`` planes and a midpoint
-    correction of the truncated tail is applied (MDR-style unbiased decode)."""
+    correction of the truncated tail is applied (MDR-style unbiased decode).
+
+    ``planes_kept`` is a traced argument: one compiled program per piece
+    shape serves every precision a progressive read reaches."""
     p = mag_bits if planes_kept is None else planes_kept
+    return _align_decode(mag, sign, e, jnp.uint32(max(mag_bits - p, 0)),
+                         mag_bits)
+
+
+@functools.partial(jax.jit, static_argnames=("mag_bits",))
+def _align_decode(mag, sign, e, tail, mag_bits):
     mag = mag.astype(jnp.uint32)
-    if p < mag_bits:
-        tail = mag_bits - p
-        mag = (mag >> tail) << tail
-        # midpoint of the truncation interval; applied even at mag==0 (the
-        # sign plane travels with the first group, so sign is known).
-        mag = mag + jnp.uint32(1 << (tail - 1)) if tail >= 1 else mag
+    mag = (mag >> tail) << tail
+    # midpoint of the truncation interval; applied even at mag==0 (the
+    # sign plane travels with the first group, so sign is known).
+    half = jnp.uint32(1) << (jnp.maximum(tail, jnp.uint32(1)) - jnp.uint32(1))
+    mag = jnp.where(tail >= 1, mag + half, mag)
     scale = jnp.exp2((mag_bits - e).astype(jnp.float32))
     val = mag.astype(jnp.float32) / scale
     return jnp.where(sign > 0, -val, val)

@@ -249,12 +249,19 @@ def _rle_expand_batch(values: jax.Array, lengths: jax.Array, n: int):
 
 # ---------------------------------------------------------------- utilities --
 
-def _pad_stack(blobs: Sequence[jax.Array], length: int) -> jax.Array:
-    rows = []
-    for b in blobs:
-        pad = length - b.shape[0]
-        rows.append(jnp.pad(b, (0, pad)) if pad else b)
-    return jnp.stack(rows)
+def _bucket(n: int) -> int:
+    """Next power of two >= ``n``: decode batches and payload lengths are
+    padded to these buckets, so the jitted decoders compile once per bucket
+    instead of once per distinct compressed size."""
+    return 1 << max(int(n) - 1, 0).bit_length()
+
+
+def _pad_rows_host(rows: Sequence[np.ndarray], length: int) -> np.ndarray:
+    """Stack 1-D host rows into a zero-padded (len(rows), length) array."""
+    out = np.zeros((len(rows), length), dtype=rows[0].dtype)
+    for j, r in enumerate(rows):
+        out[j, :r.shape[0]] = r
+    return out
 
 
 def batch_jobs(items, key) -> Dict[tuple, List[int]]:
@@ -499,10 +506,10 @@ def _encode_buckets(stacked: Dict[tuple, jax.Array],
         def rows_for(sel_idx: List[int]) -> jax.Array:
             # codec row selection: host take + upload when the rows are
             # already host-side (CPU stats path), device gather otherwise
+            sel = [pos[i] for i in sel_idx]
             if rows_host is not None:
-                return jax.device_put(
-                    rows_host[k][np.asarray([pos[i] for i in sel_idx])])
-            return st[jnp.asarray([pos[i] for i in sel_idx], jnp.int32)]
+                return jax.device_put(rows_host[k][np.asarray(sel)])
+            return st[jnp.asarray(sel, jnp.int32)]
 
         if h:
             lens_tab = jax.device_put(
@@ -524,8 +531,7 @@ def _encode_buckets(stacked: Dict[tuple, jax.Array],
                                          {"raw": rows_host[k][pos[i]].copy()},
                                          {"n_syms": s})
             else:
-                pend.append(("dc", s, d, st[jnp.asarray(
-                    [pos[i] for i in d], jnp.int32)]))
+                pend.append(("dc", s, d, rows_for(d)))
     mats = host_sync([p[3] for p in pend], label="codec.payload")
 
     for (kind, s, idxs, _), mat in zip(pend, mats):
@@ -607,25 +613,33 @@ def decode_segments(segs: Sequence[ll.Segment]) -> List[np.ndarray]:
                 segs[i].payload["lengths"],
                 ll._codes_from_lengths(segs[i].payload["lengths"]))
                 for i in idxs]
-            words = _pad_stack(
-                [jnp.asarray(segs[i].payload["words"]) for i in idxs],
-                max(segs[i].payload["words"].shape[0] for i in idxs))
-            chunk_offs = jnp.stack(
-                [jnp.asarray(segs[i].payload["chunk_offs"]) for i in idxs])
-            lut_sym = jnp.asarray(np.stack([l[0] for l in luts]))
-            lut_len = jnp.asarray(np.stack([l[1] for l in luts]))
+            # rows repeat the first row to the batch bucket; their outputs
+            # are discarded
+            rows = idxs + [idxs[0]] * (_bucket(len(idxs)) - len(idxs))
+            words = _pad_rows_host(
+                [segs[i].payload["words"] for i in rows],
+                _bucket(max(segs[i].payload["words"].shape[0]
+                            for i in idxs)))
+            chunk_offs = np.stack([segs[i].payload["chunk_offs"]
+                                   for i in rows])
+            luts += [luts[0]] * (len(rows) - len(idxs))
+            lut_sym = np.stack([l[0] for l in luts])
+            lut_len = np.stack([l[1] for l in luts])
             STATS.add(huffman_unpack_batches=1)
             pending.append((idxs, _huffman_unpack_batch(
-                words, chunk_offs, lut_sym, lut_len, n)))
+                *jax.device_put((words, chunk_offs, lut_sym, lut_len)), n)))
         elif method == "rle":
-            rmax = max(segs[i].payload["values"].shape[0] for i in idxs)
-            values = _pad_stack(
-                [jnp.asarray(segs[i].payload["values"]) for i in idxs], rmax)
-            lengths = _pad_stack(
-                [jnp.asarray(segs[i].payload["lengths"].astype(np.int32))
-                 for i in idxs], rmax)
+            rows = idxs + [idxs[0]] * (_bucket(len(idxs)) - len(idxs))
+            rmax = _bucket(max(segs[i].payload["values"].shape[0]
+                               for i in idxs))
+            # zero-length padding runs never cover a symbol
+            values = _pad_rows_host([segs[i].payload["values"]
+                                     for i in rows], rmax)
+            lengths = _pad_rows_host([segs[i].payload["lengths"].astype(
+                np.int32) for i in rows], rmax)
             STATS.add(rle_expand_batches=1)
-            pending.append((idxs, _rle_expand_batch(values, lengths, n)))
+            pending.append((idxs, _rle_expand_batch(
+                *jax.device_put((values, lengths)), n)))
         else:
             raise ValueError(f"cannot decode method {method!r}")
 

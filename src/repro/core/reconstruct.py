@@ -12,8 +12,8 @@ rebuild.
 device:
 
   * ``mag``   — accumulated uint32 magnitudes.  Newly fetched plane groups
-    are decoded *at their bit offsets* (``kernels.ops.decode_bitplanes_offset
-    (_batch)``) and OR-ed in; disjoint bit ranges make the accumulation exact,
+    are decoded *at their bit offsets* (``kernels.ops.decode_bitplanes_offset_batch``)
+    and OR-ed in; disjoint bit ranges make the accumulation exact,
     so the magnitudes are bit-identical to a full-stack decode.
   * ``sign``  — decoded once, with the piece's first group.
   * ``value`` — the align-decoded float32 coefficients, refreshed only for

@@ -56,10 +56,7 @@ from repro.core import refactor_fused as rff
 from repro.obs import trace as obs_trace
 from repro import tune as tn
 
-try:  # jax >= 0.4: canonical home of Mesh
-    from jax.sharding import Mesh
-except ImportError:  # pragma: no cover - ancient jax
-    from jax.interpreters.pxla import Mesh  # type: ignore
+from jax.sharding import Mesh
 
 MeshLike = Union[None, int, Mesh]
 

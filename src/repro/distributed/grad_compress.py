@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import axis_size as _axis_size
-from repro.distributed.sharding import shard_map as _shard_map
 
 from repro.kernels import ref as kref
 
@@ -78,7 +76,7 @@ def compressed_psum(x: jax.Array, axis_name: str, planes: int = 8
 
     Returns (reduced, residual): `residual` is THIS device's truncation error
     on its reduce-scatter shard (for error feedback)."""
-    n_dev = _axis_size(axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
     flat = x.astype(jnp.float32).reshape(-1)
     pad = (-flat.shape[0]) % (n_dev * 4096)
     flat = jnp.pad(flat, (0, pad))
@@ -100,7 +98,7 @@ def compressed_psum(x: jax.Array, axis_name: str, planes: int = 8
 def make_compressed_allreduce(mesh, axis_name: str, planes: int = 8):
     """jit-ready f(x) -> (mean_over_axis, residual_shard) via shard_map."""
     @functools.partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=P(axis_name), out_specs=(P(axis_name), P(axis_name)),
     )
     def f(x_shard):

@@ -84,30 +84,6 @@ def named(pspec: P) -> Optional[NamedSharding]:
     return NamedSharding(mesh, pspec)
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: Optional[bool] = None):
-    """jax.shard_map across jax versions.
-
-    jax >= 0.6 exposes ``jax.shard_map(..., check_vma=)``; 0.4.x has it at
-    ``jax.experimental.shard_map.shard_map(..., check_rep=)``.  All in-repo
-    call sites go through this wrapper so version skew is handled once."""
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if hasattr(jax, "shard_map"):
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        return jax.shard_map(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as _sm
-    if check_vma is not None:
-        kwargs["check_rep"] = check_vma
-    return _sm(f, **kwargs)
-
-
-def axis_size(axis_name: str) -> int:
-    """Static mesh-axis size inside shard_map (jax.lax.axis_size is >= 0.5)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return int(jax.lax.psum(1, axis_name))
-
-
 def dp_size(mesh: Optional[Mesh] = None) -> int:
     mesh = mesh or get_current_mesh()
     n = 1

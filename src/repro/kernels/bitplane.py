@@ -13,15 +13,17 @@ TPU adaptation (see DESIGN.md §2):
 
 ``locality`` (paper §4.1)
     Input tile (128, 32): each sublane-row owns 32 *consecutive* elements
-    (one output word).  The narrow 32-lane block and the cross-lane
-    reduction are the TPU analogue of the design's uncoalesced loads; it
-    preserves bit-order locality (better downstream compressibility).
+    (one output word).  The narrow 32-lane block is the TPU analogue of the
+    design's uncoalesced loads; an in-register transpose puts a word's bits
+    on sublanes and a sublane reduction assembles it.  It preserves
+    bit-order locality (better downstream compressibility).
 
 ``shuffle`` (paper §4.2)
-    Same (128, 32) layout, but the word is assembled with a log2(32)-step
-    cross-lane shift tree (``pltpu.roll``) — the TPU-native analogue of the
-    warp shift-reduce.  Warp ``ballot``/``match-any``/``redux`` have no TPU
-    equivalent (no warp-collective datapath); documented in DESIGN.md.
+    Same (128, 32) layout and transpose, but the word is assembled with a
+    log2(32)-step shift tree of sublane rotations (``pltpu.roll``) — the
+    TPU-native analogue of the warp shift-reduce.  Warp
+    ``ballot``/``match-any``/``redux`` have no TPU equivalent (no
+    warp-collective datapath).
 
 Formats match ``ref.py`` bit-exactly (portability contract): `locality` and
 `shuffle` share the consecutive-element format; `register_block` uses the
@@ -34,6 +36,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 TILE_SUB = 32
 TILE_LANE = 128
@@ -119,42 +122,52 @@ def _decode_register_block_kernel(p_ref, out_ref, *, num_planes_total: int,
 # ------------------------------------------------------------ locality ----
 
 def _encode_locality_kernel(x_ref, out_ref, *, num_planes: int, tiles: int):
-    x = _u32(x_ref[...])  # (128*tiles, 32): row = one word's 32 consecutive elems
-    weights = (jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32))[None, :]
+    # block (128*tiles, 32): row = one word's 32 consecutive elements.  The
+    # in-register transpose puts the 32 bits of a word on sublanes, so each
+    # plane word is a sublane reduction into a lane-dense row.  The OR-fold
+    # is an int32 sum (the bits are disjoint) bitcast back: the TPU compiler
+    # implements no unsigned reductions.
+    x = _u32(x_ref[...]).T  # (32, 128*tiles)
+    shifts = jax.lax.broadcasted_iota(jnp.uint32, x.shape, 0)
     for j in range(num_planes):
         b = jnp.uint32(num_planes - 1 - j)
-        bits = (x >> b) & jnp.uint32(1)
-        out_ref[j, :] = jnp.sum(bits * weights, axis=1).astype(jnp.uint32)
+        w = ((x >> b) & jnp.uint32(1)) << shifts
+        s = jnp.sum(jax.lax.bitcast_convert_type(w, jnp.int32), axis=0)
+        out_ref[j, :] = jax.lax.bitcast_convert_type(s, jnp.uint32)
 
 
 def _decode_locality_kernel(p_ref, out_ref, *, num_planes_total: int, tiles: int):
+    # bits are built word-per-lane (32, 128*tiles) by a sublane broadcast of
+    # each plane row, then transposed once into the (128*tiles, 32)
+    # consecutive-element output block
     p = _u32(p_ref[...])  # (P, 128*tiles)
-    P = p.shape[0]
-    shifts = jnp.arange(32, dtype=jnp.uint32)[None, :]
-    acc = jnp.zeros((p.shape[1], 32), jnp.uint32)
+    P, W = p.shape
+    shifts = jax.lax.broadcasted_iota(jnp.uint32, (32, W), 0)
+    acc = jnp.zeros((32, W), jnp.uint32)
     for j in range(P):
         b = jnp.uint32(num_planes_total - 1 - j)
-        bits = (p[j, :, None] >> shifts) & jnp.uint32(1)
-        acc = acc | (bits << b)
-    out_ref[...] = acc
+        acc = acc | (((p[j:j + 1, :] >> shifts) & jnp.uint32(1)) << b)
+    out_ref[...] = acc.T
 
 
 # ------------------------------------------------------------- shuffle ----
 
 def _encode_shuffle_kernel(x_ref, out_ref, *, num_planes: int, tiles: int):
-    """Shift-tree word assembly across the 32-lane axis (warp-shuffle analogue)."""
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (roll)
-    x = _u32(x_ref[...])  # (128*tiles, 32)
-    lane = jax.lax.broadcasted_iota(jnp.uint32, x.shape, 1)
+    """Shift-tree word assembly (warp-shuffle analogue): after the in-register
+    transpose, element i of each word sits on sublane i and a log2(32)-step
+    OR tree of sublane rotations (``pltpu.roll``) folds the word into row 0.
+    Rotating the narrow 32-lane layout instead compiles for minutes at
+    ``tiles_per_block`` >= 8."""
+    x = _u32(x_ref[...]).T  # (32, 128*tiles)
+    shifts = jax.lax.broadcasted_iota(jnp.uint32, x.shape, 0)
     for j in range(num_planes):
         b = jnp.uint32(num_planes - 1 - j)
-        w = ((x >> b) & jnp.uint32(1)) << lane  # thread i contributes bit i
+        w = ((x >> b) & jnp.uint32(1)) << shifts  # thread i contributes bit i
         s = 16
         while s >= 1:
-            # tree-reduce OR across lanes (roll is a cyclic lane shift)
-            w = w | jnp.roll(w, -s, axis=1)
+            w = w | pltpu.roll(w, 32 - s, 0)  # row r gathers row r + s
             s //= 2
-        out_ref[j, :] = w[:, 0]
+        out_ref[j, :] = w[0, :]
 
 
 # ------------------------------------------------------------ wrappers ----

@@ -109,14 +109,12 @@ def _shard_batch(fn, batch: jax.Array, mesh, axis: str):
     per-call statics bound."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed import sharding as shd  # local: keep graph flat
-
     size = mesh.shape[axis]
     if int(batch.shape[0]) % size != 0:
         raise ValueError(
             f"batch dim {batch.shape[0]} not divisible by mesh axis "
             f"{axis!r} of size {size}")
-    return shd.shard_map(fn, mesh=mesh, in_specs=P(axis),
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(axis),
                          out_specs=P(axis), check_vma=False)(batch)
 
 
@@ -151,37 +149,41 @@ def decode_bitplanes_sharded(planes: jax.Array, num_planes_total: int, n: int,
         planes, mesh, axis)
 
 
-def decode_bitplanes_offset(planes: jax.Array, num_planes_total: int, n: int,
-                            plane_offset: int,
-                            design: str = "register_block",
-                            backend: str = _DEFAULT_BACKEND,
-                            tiles_per_block: int = 8,
-                            unroll: str = "butterfly") -> jax.Array:
-    """Decode a plane-group slice that sits at ``plane_offset`` rows into the
-    MSB-first stack: row ``j`` of ``planes`` carries magnitude bit
-    ``num_planes_total - 1 - (plane_offset + j)``.
-
-    The returned (n,) uint32 magnitudes hold ONLY those bits — OR-ing the
-    results of disjoint slices reproduces the full-stack decode exactly
-    (integer bits are disjoint), which is what makes the incremental read
-    path (``core.reconstruct``) bit-exact with the full-decode oracle.
-
-    Implemented as a truncated-total decode: shifting the total by the offset
-    shifts every row's bit position identically, so the existing kernels (and
-    their jit caches, Pallas included) are reused as-is."""
-    return decode_bitplanes(planes, num_planes_total - plane_offset, n,
-                            design, backend, tiles_per_block, unroll)
-
-
 def decode_bitplanes_offset_batch(planes: jax.Array, num_planes_total: int,
                                   n: int, plane_offset: int,
                                   design: str = "register_block",
                                   backend: str = _DEFAULT_BACKEND,
                                   tiles_per_block: int = 8,
                                   unroll: str = "butterfly") -> jax.Array:
-    """(B, P, W) same-offset plane-group slices -> (B, n) partial magnitudes:
-    the batched form of ``decode_bitplanes_offset`` (one vmapped launch).
-    Used by the incremental reconstruction engine to decode newly fetched
-    groups across pieces, chunks, variables, and sessions in one call."""
-    return decode_bitplanes_batch(planes, num_planes_total - plane_offset, n,
-                                  design, backend, tiles_per_block, unroll)
+    """(B, P, W) same-offset plane-group slices -> (B, n) partial magnitudes
+    in one vmapped launch.  Row ``j`` of each slice sits ``plane_offset``
+    rows into the MSB-first stack and carries magnitude bit
+    ``num_planes_total - 1 - (plane_offset + j)``.
+
+    The magnitudes hold ONLY those bits — OR-ing the results of disjoint
+    slices reproduces the full-stack decode exactly (integer bits are
+    disjoint), which is what makes the incremental read path
+    (``core.reconstruct``) bit-exact with the full-decode oracle.  Used to
+    decode newly fetched groups across pieces, chunks, variables, and
+    sessions in one call.
+
+    Implemented as a decode of the slice alone (its rows land on bits
+    ``P-1 .. 0``) followed by a left shift by the slice's depth below the
+    top, ``num_planes_total - plane_offset - P``.  The shift is a traced
+    operand, so one compiled program per slice shape serves every offset."""
+    shift = num_planes_total - plane_offset - int(planes.shape[1])
+    if shift < 0:
+        raise ValueError(
+            f"{planes.shape[1]} planes at offset {plane_offset} overrun a "
+            f"{num_planes_total}-plane stack")
+    return _decode_shifted_batch(planes, jnp.uint32(shift), n, design,
+                                 backend, tiles_per_block, unroll)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "design", "backend",
+                                             "tiles_per_block", "unroll"))
+def _decode_shifted_batch(planes: jax.Array, shift: jax.Array, n: int,
+                          design: str, backend: str, tiles_per_block: int,
+                          unroll: str) -> jax.Array:
+    return decode_bitplanes_batch(planes, int(planes.shape[1]), n, design,
+                                  backend, tiles_per_block, unroll) << shift

@@ -6,7 +6,18 @@ before any jax import; everything else sees the real device count.
 """
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape: Sequence[int], axes: Sequence[str]):
+    """``jax.make_mesh`` with Auto axes.  ``jax.make_mesh`` builds Explicit
+    axes by default; the model code's sharding rules
+    (``distributed.sharding.constraint``, implicit gathers) assume Auto."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,7 +25,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     across two pods (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1, pod: int = 1):
@@ -24,4 +35,4 @@ def make_host_mesh(data: int = 1, model: int = 1, pod: int = 1):
     axes = ("pod", "data", "model")
     if pod == 1:
         shape, axes = (data, model), ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)

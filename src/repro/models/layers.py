@@ -472,8 +472,7 @@ def seqshard_decode_gqa(q, k_cache, v_cache, k_new, v_new, index,
     All heads are computed on every model shard (decode is memory-bound; the
     cache READ is the cost and it is perfectly sharded — wire traffic is one
     (B,1,H,dv)+LSE psum per layer instead of a 1/16-replicated cache)."""
-    from repro.distributed.sharding import (get_current_mesh, shard_map,
-                                             spec as shspec)
+    from repro.distributed.sharding import get_current_mesh, spec as shspec
     from jax.sharding import PartitionSpec as P
     mesh = get_current_mesh()
     b_ax = tuple(batch_axes) if batch_axes else None
@@ -493,7 +492,7 @@ def seqshard_decode_gqa(q, k_cache, v_cache, k_new, v_new, index,
         out = (acc_g / jnp.maximum(l_b, 1e-30)).astype(qs.dtype)
         return out.reshape(qs.shape[0], 1, qs.shape[2], vc.shape[-1]), kc, vc
 
-    smap = shard_map(
+    smap = jax.shard_map(
         body, mesh=mesh,
         in_specs=(q_spec, cache_spec, cache_spec, q_spec, q_spec, P()),
         out_specs=(q_spec, cache_spec, cache_spec),
@@ -506,8 +505,7 @@ def seqshard_decode_gqa_int8(q, k_cache, v_cache, ks_cache, vs_cache,
                              *, scale=None):
     """Flash-decoding over an int8, per-(token,head)-aligned KV cache
     (HP-MDR alignment on serving state): cache reads are half the bytes."""
-    from repro.distributed.sharding import (get_current_mesh, shard_map,
-                                             spec as shspec)
+    from repro.distributed.sharding import get_current_mesh, spec as shspec
     from jax.sharding import PartitionSpec as P
     mesh = get_current_mesh()
     b_ax = tuple(batch_axes) if batch_axes else None
@@ -533,7 +531,7 @@ def seqshard_decode_gqa_int8(q, k_cache, v_cache, ks_cache, vs_cache,
         return (out.reshape(qs.shape[0], 1, qs.shape[2], vc.shape[-1]),
                 kc, vc, ksc, vsc)
 
-    smap = shard_map(
+    smap = jax.shard_map(
         body, mesh=mesh,
         in_specs=(q_spec, cache_spec, cache_spec, scale_spec, scale_spec,
                   q_spec, q_spec, new_scale_spec, new_scale_spec, P()),

@@ -123,7 +123,7 @@ def _moe_apply_shard_map(p, x: jax.Array, cfg: ModelConfig, mesh, tp: int):
     local (E/tp, C, D) buffer from replicated tokens — zero dispatch
     collectives; the combine is a single (T,D) psum, identical to a Megatron
     FFN's.  Routing (and the aux loss) stays outside in GSPMD-land."""
-    from repro.distributed.sharding import shard_map, spec as shspec
+    from repro.distributed.sharding import spec as shspec
     from jax.sharding import PartitionSpec as P
     m: MoEConfig = cfg.moe
     cdt = jnp.dtype(cfg.compute_dtype)
@@ -161,7 +161,7 @@ def _moe_apply_shard_map(p, x: jax.Array, cfg: ModelConfig, mesh, tp: int):
         out_l = jnp.zeros((t_l, d), cdt).at[tok_flat].add(gathered * w_keep)
         return jax.lax.psum(out_l, "model")
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(tok_spec, route_spec, route_spec) + w_specs,
         out_specs=tok_spec, check_vma=False,

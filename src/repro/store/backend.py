@@ -49,6 +49,10 @@ class BackendStats:
     prefetch_useful: int = 0
     # prefetch hints shed by the bounded queue (oldest-first) under bursts
     prefetch_dropped: int = 0
+    # inner reads that raised, whoever issued them: the best-effort warm
+    # and prefetch paths swallow their errors, so this count is what shows
+    # a failure that no request surfaced
+    fetch_errors: int = 0
 
     def __post_init__(self):
         self._lock = threading.Lock()
@@ -244,6 +248,7 @@ class CachingBackend(FetchBackend):
             try:
                 data = self.inner.read(key, off, size)
             except BaseException as exc:
+                self.stats.add(fetch_errors=1)
                 # publish-then-wake ordering: waiters read fl.error after
                 # event.wait(), so the error must be set before event.set()
                 fl.error = exc

@@ -455,11 +455,20 @@ class ServingTier:
                 self._jobs.pop(t, None)
         return batch
 
-    def _upload(self, job: DecodeJob) -> jax.Array:
-        rows = np.ascontiguousarray(job.rows, dtype=np.uint32)
-        if job.device is None:
-            return jnp.asarray(rows)
-        return jax.device_put(rows, job.device)
+    @staticmethod
+    def _upload_stack(bucket: List[DecodeJob]) -> jax.Array:
+        """Stack a bucket's host rows into one upload to the bucket's
+        device.  The batch is padded to a power of two by repeating its
+        first job (those outputs are never read), so the vmapped decode
+        compiles once per batch bucket, not once per batch size."""
+        rows = [np.ascontiguousarray(j.rows, dtype=np.uint32)
+                for j in bucket]
+        rows += [rows[0]] * (lb._bucket(len(rows)) - len(rows))
+        stacked = np.stack(rows)
+        device = bucket[0].device
+        if device is None:
+            return jnp.asarray(stacked)
+        return jax.device_put(stacked, device)
 
     def _decode_round(self, batch: List[DecodeJob]) -> None:
         """One combined decode: bucket the round's jobs exactly as
@@ -483,7 +492,7 @@ class ServingTier:
                     unroll, _dev = k
                 bucket = [groups[p] for p in pos]
                 try:
-                    stacked = jnp.stack([self._upload(j) for j in bucket])
+                    stacked = self._upload_stack(bucket)
                     mags = kops.decode_bitplanes_offset_batch(
                         stacked, mag_bits, n, offset, design, backend=bk,
                         tiles_per_block=tiles, unroll=unroll)
@@ -504,7 +513,7 @@ class ServingTier:
                 words, n, design, bk, tiles, unroll, _dev = k
                 bucket = [signs[p] for p in pos]
                 try:
-                    stacked = jnp.stack([self._upload(j) for j in bucket])
+                    stacked = self._upload_stack(bucket)
                     sgs = kops.decode_bitplanes_batch(
                         stacked, 1, n, design, backend=bk,
                         tiles_per_block=tiles, unroll=unroll)

@@ -9,12 +9,13 @@ hardware peaks::
 
     t_model = max(flops / peak_flops, bytes / hbm_bw) + wire / link_bw
 
-Absolute peaks are nominal per platform (``NOMINAL_PEAKS`` — the TPU row is
-the same v5e numbers ``benchmarks/roofline.py`` publishes; that module
-imports them from here so the calibration artifact and the cost model can
-never disagree).  Absolute accuracy does not matter for the tuner: the model
-only *ranks* candidates, and the few measured probe runs
-(``repro.tune.search``) both calibrate the scale and decide the winner.
+Absolute peaks come from one table keyed by jax ``device_kind``
+(``PEAKS``); ``benchmarks/roofline.py`` reads the same table, so the
+calibration artifact and the cost model can never disagree.  A device that
+is not in the table is an error, never a silent default.  Absolute accuracy
+does not matter for the tuner: the model only *ranks* candidates, and the
+few measured probe runs (``repro.tune.search``) both calibrate the scale and
+decide the winner.
 """
 from __future__ import annotations
 
@@ -26,27 +27,35 @@ from typing import Dict, Optional, Sequence, Tuple
 
 from repro.tune.config import RefactorConfig
 
-# nominal hardware peaks per jax platform (flops/s, HBM bytes/s, link
-# bytes/s).  TPU: v5e-class chip — the numbers benchmarks/roofline.py
-# publishes.  CPU/GPU rows are order-of-magnitude placeholders; probe
-# calibration absorbs the error.
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
-
 
 @dataclasses.dataclass(frozen=True)
 class Peaks:
-    flops: float
-    hbm_bw: float
-    link_bw: float
+    flops: float     # FLOP/s
+    hbm_bw: float    # HBM bytes/s
+    link_bw: float   # chip-to-chip bytes/s
 
 
-NOMINAL_PEAKS: Dict[str, Peaks] = {
-    "tpu": Peaks(PEAK_FLOPS, HBM_BW, LINK_BW),
-    "gpu": Peaks(60e12, 2e12, 100e9),
+# jax ``device_kind`` of a TPU v5e chip
+V5E = "TPU v5 lite"
+
+PEAKS: Dict[str, Peaks] = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+    # 819 GB/s, 1,600 Gbit/s (200 GB/s) inter-chip interconnect
+    V5E: Peaks(197e12, 819e9, 200e9),
+    # the host backend the CPU test suite runs on: order-of-magnitude rates
+    # (no published source), kept only so the tuner runs in those tests
     "cpu": Peaks(1e11, 3e10, 1e10),
 }
+
+
+def nominal_peaks(device_kind: str) -> Peaks:
+    """The ``PEAKS`` row of ``device_kind``; raises for an unknown device."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peaks for device kind {device_kind!r}: add its published "
+            f"figures, with their source, to repro.tune.cost.PEAKS") from None
 
 
 # where a machine's measured roofline calibration lives; overridable so CI
@@ -56,7 +65,7 @@ DEFAULT_ROOFLINE_ARTIFACT = os.path.join("out", "benchmarks",
                                          "roofline.json")
 
 
-def calibrated_peaks(platform: str,
+def calibrated_peaks(device_kind: str,
                      path: Optional[str] = None) -> Optional[Peaks]:
     """This machine's measured effective peaks from its roofline artifact.
 
@@ -64,12 +73,11 @@ def calibrated_peaks(platform: str,
     ``calibrated`` section — nominal peaks divided by the fitted model
     scale, i.e. the peak rates at which THIS machine actually moved the
     program's bytes/flops.  When the artifact exists and matches the
-    platform, the cost model starts from those instead of the hard-coded
-    nominal constants (ROADMAP autotuner-deepening item), so candidate
-    rankings reflect the machine rather than a v5e spec sheet.
+    device kind, the cost model starts from those instead of the nominal
+    table, so candidate rankings reflect the machine.
 
     Returns ``None`` (nominal fallback) when the artifact is absent,
-    unreadable, for another platform, or carries non-finite/zero rates —
+    unreadable, for another device kind, or carries non-finite/zero rates —
     a corrupt artifact must never poison the tuner."""
     path = path if path is not None else os.environ.get(
         ROOFLINE_ARTIFACT_ENV, DEFAULT_ROOFLINE_ARTIFACT)
@@ -77,7 +85,7 @@ def calibrated_peaks(platform: str,
         with open(path, "r", encoding="utf-8") as f:
             doc = json.load(f)
         cal = doc["calibrated"]
-        if cal.get("platform") != platform:
+        if cal.get("device_kind") != device_kind:
             return None
         peaks = Peaks(float(cal["flops"]), float(cal["hbm_bw"]),
                       float(cal["link_bw"]))
@@ -89,16 +97,17 @@ def calibrated_peaks(platform: str,
     return peaks
 
 
-def platform_peaks(platform: Optional[str] = None) -> Peaks:
-    """Peaks for scoring: the machine's calibrated roofline artifact when
-    one is present (``calibrated_peaks``), else the nominal platform row."""
-    if platform is None:
+def device_peaks(device_kind: Optional[str] = None) -> Peaks:
+    """Peaks for scoring on ``device_kind`` (default: this process's first
+    device): the machine's calibrated roofline artifact when one is present
+    (``calibrated_peaks``), else the nominal ``PEAKS`` row.  Raises for a
+    device kind the table does not know."""
+    if device_kind is None:
         import jax
-        platform = jax.default_backend()
-    cal = calibrated_peaks(platform)
-    if cal is not None:
-        return cal
-    return NOMINAL_PEAKS.get(platform, NOMINAL_PEAKS["cpu"])
+        device_kind = jax.devices()[0].device_kind
+    nominal = nominal_peaks(device_kind)
+    cal = calibrated_peaks(device_kind)
+    return cal if cal is not None else nominal
 
 
 def fused_program_hlo(shape: Sequence[int], levels: Optional[int],
@@ -164,7 +173,7 @@ class CostModel:
         self.shape = tuple(int(d) for d in shape)
         self.levels = levels
         self.dtype = dtype
-        self.peaks = peaks if peaks is not None else platform_peaks()
+        self.peaks = peaks if peaks is not None else device_peaks()
         self.scale = 1.0
         self._cache: Dict[Tuple, ProgramCost] = {}
 

@@ -21,6 +21,11 @@
      blobs (overlap look-ahead + per-device drain window);
   4. cache the measured winner keyed by backend fingerprint.
 
+A candidate that fails to lower, compile or run is not eligible, but it is
+never dropped in silence: every failure is logged, counted
+(``STATS.candidates_failed``), returned in ``TuneResult.failures`` with its
+stage and error, and recorded in the cache entry's metadata.
+
 The measured-best-of-probes rule keeps the tuner safe: the default config is
 always a probe, so a tuned config can only tie or beat it on the probe
 workload — never regress it on the machine that tuned.
@@ -31,10 +36,11 @@ are produced, not which bytes the user asked to keep.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -48,6 +54,8 @@ TILES = (4, 8, 16)
 DISPATCH_AHEAD = (1, 2, 4)
 DEPTHS = (1, 2, 4)  # read-side overlap look-ahead / drain window
 
+logger = logging.getLogger("repro.tune")
+
 
 @dataclasses.dataclass
 class SearchStats:
@@ -55,6 +63,7 @@ class SearchStats:
     actual cost-model searches — a cache hit must NOT increment it."""
     searches: int = 0
     candidates_scored: int = 0
+    candidates_failed: int = 0
     probes_run: int = 0
 
     def __post_init__(self):
@@ -80,6 +89,31 @@ STATS = SearchStats()
 
 
 @dataclasses.dataclass(frozen=True)
+class CandidateFailure:
+    """One candidate the search could not use, and why."""
+    config: RefactorConfig
+    stage: str      # 'score' | 'probe' | 'dispatch_ahead' | 'depth'
+    error: str      # ``repr`` of the exception
+
+    def to_json(self) -> Dict[str, Any]:
+        return {"config": self.config.to_json(), "stage": self.stage,
+                "error": self.error}
+
+
+def _attempt(failures: List[CandidateFailure], cfg: RefactorConfig,
+             stage: str, fn: Callable[[], Any]) -> Optional[Any]:
+    """Run ``fn``; on failure record a ``CandidateFailure`` (logged and
+    counted) and return ``None``."""
+    try:
+        return fn()
+    except Exception as exc:  # noqa: BLE001 - recorded, logged, counted
+        failures.append(CandidateFailure(cfg, stage, repr(exc)))
+        STATS.add(candidates_failed=1)
+        logger.warning("tune: %s of %s failed: %r", stage, cfg, exc)
+        return None
+
+
+@dataclasses.dataclass(frozen=True)
 class TuneResult:
     config: RefactorConfig
     cache_hit: bool
@@ -89,6 +123,8 @@ class TuneResult:
     scores: Tuple[Tuple[RefactorConfig, float], ...] = ()
     # (config, measured_seconds) for every probe; empty on cache hit
     probes: Tuple[Tuple[RefactorConfig, float], ...] = ()
+    # every candidate that failed to lower, compile or run; empty on hit
+    failures: Tuple[CandidateFailure, ...] = ()
     tune_s: float = 0.0
 
 
@@ -202,6 +238,7 @@ def _probe_blobs(best: RefactorConfig, n: int, levels: Optional[int],
 
 def _tune_read_depth(best: RefactorConfig, shape: Sequence[int],
                      dtype: str, levels: Optional[int],
+                     failures: List[CandidateFailure],
                      n_chunks: int = 6
                      ) -> Tuple[RefactorConfig,
                                 List[Tuple[RefactorConfig, float]]]:
@@ -224,20 +261,21 @@ def _tune_read_depth(best: RefactorConfig, shape: Sequence[int],
     if levels is None:
         from repro.core import decompose as dc
         levels = dc.num_levels((n,))
-    try:
-        x, blobs = _probe_blobs(best, n, levels, dtype, n_chunks)
-    except Exception:
+    probe = _attempt(failures, best, "depth",
+                     lambda: _probe_blobs(best, n, levels, dtype, n_chunks))
+    if probe is None:
         return fallback, []
+    x, blobs = probe
     # mid-curve tolerance: deep enough that every chunk fetches several
     # plane groups (the staged-drain schedule depth actually controls)
     tol = 1e-3 * float(np.ptp(x)) if np.ptp(x) > 0 else 1e-3
     timed: List[Tuple[RefactorConfig, float]] = []
     for dp in DEPTHS:
         cfg = best.replace(depth=dp, chunk_elems=n)
-        try:
-            timed.append((cfg, _measure_pipeline_read(blobs, cfg, tol)))
-        except Exception:
-            continue
+        t = _attempt(failures, cfg, "depth",
+                     lambda: _measure_pipeline_read(blobs, cfg, tol))
+        if t is not None:
+            timed.append((cfg, t))
     if not timed:
         return fallback, []
     dp = min(timed, key=lambda cs: cs[1])[0].depth
@@ -247,6 +285,7 @@ def _tune_read_depth(best: RefactorConfig, shape: Sequence[int],
 
 def _tune_dispatch_ahead(best_prog: RefactorConfig, shape: Sequence[int],
                          dtype: str, levels: Optional[int],
+                         failures: List[CandidateFailure],
                          n_chunks: int = 6
                          ) -> Tuple[RefactorConfig,
                                     List[Tuple[RefactorConfig, float]]]:
@@ -270,10 +309,10 @@ def _tune_dispatch_ahead(best_prog: RefactorConfig, shape: Sequence[int],
     timed: List[Tuple[RefactorConfig, float]] = []
     for da in DISPATCH_AHEAD:
         cfg = best_prog.replace(dispatch_ahead=da, chunk_elems=n)
-        try:
-            timed.append((cfg, _measure_pipeline_write(x, cfg, levels)))
-        except Exception:
-            continue
+        t = _attempt(failures, cfg, "dispatch_ahead",
+                     lambda: _measure_pipeline_write(x, cfg, levels))
+        if t is not None:
+            timed.append((cfg, t))
     if not timed:
         return fallback, []
     da = min(timed, key=lambda cs: cs[1])[0].dispatch_ahead
@@ -311,13 +350,13 @@ def tune(shape: Sequence[int], dtype: str = "float32",
     cands = candidate_space(base, kops._resolve(backend))
 
     model = CostModel(shape, levels, dtype)
+    failures: List[CandidateFailure] = []
     scored: List[Tuple[RefactorConfig, float]] = []
     for c in cands:
-        try:
-            scored.append((c, model.score(c)))
-        except Exception:
-            # a candidate that fails to lower/compile is simply not eligible
-            continue
+        # a candidate that fails to lower/compile is not eligible
+        t = _attempt(failures, c, "score", lambda: model.score(c))
+        if t is not None:
+            scored.append((c, t))
     STATS.add(candidates_scored=len(scored))
     scored.sort(key=lambda cs: cs[1])
 
@@ -334,10 +373,10 @@ def tune(shape: Sequence[int], dtype: str = "float32",
     x = _probe_chunk(shape, dtype)
     measured: List[Tuple[RefactorConfig, float]] = []
     for c in probe_set:
-        try:
-            measured.append((c, _measure_write(x, c, levels)))
-        except Exception:
-            continue
+        t = _attempt(failures, c, "probe",
+                     lambda: _measure_write(x, c, levels))
+        if t is not None:
+            measured.append((c, t))
     if not measured:            # pathological: keep the default, cache it
         measured = [(base, float("inf"))]
     model.calibrate(base, measured[0][1])
@@ -350,11 +389,12 @@ def tune(shape: Sequence[int], dtype: str = "float32",
     # machine cannot be trusted to probe more: keep the default window.
     if np.isfinite(min(s for _, s in measured)):
         best, da_probes = _tune_dispatch_ahead(best_prog, shape, dtype,
-                                               levels)
+                                               levels, failures)
         # read-side scheduling twin: probe `depth` through the real
         # pipelined read of the winner's own blobs (bit-identical at any
         # depth — only wall clock distinguishes the candidates)
-        best, depth_probes = _tune_read_depth(best, shape, dtype, levels)
+        best, depth_probes = _tune_read_depth(best, shape, dtype, levels,
+                                              failures)
     else:
         best = (best_prog if best_prog.dispatch_ahead in DISPATCH_AHEAD
                 else best_prog.replace(dispatch_ahead=DISPATCH_AHEAD[1]))
@@ -369,9 +409,10 @@ def tune(shape: Sequence[int], dtype: str = "float32",
                                   for c, s in da_probes],
               "depth_probes": [[c.depth, s] for c, s in depth_probes],
               "model_scale": model.scale,
-              "n_candidates": len(cands)},
+              "n_candidates": len(cands),
+              "failures": [f.to_json() for f in failures]},
         root=cache_root)
     return TuneResult(config=best, cache_hit=False, fingerprint=fp,
                       problem=problem, scores=tuple(scored),
-                      probes=tuple(measured),
+                      probes=tuple(measured), failures=tuple(failures),
                       tune_s=time.perf_counter() - t0)

@@ -21,8 +21,11 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def run_devices(script: str, n_devices: int = 8, timeout: int = 900) -> str:
-    """Run `script` in a subprocess with n host devices; returns stdout."""
+    """Run `script` in a subprocess with n virtual CPU devices; returns
+    stdout.  The child is pinned to the CPU backend, so on a machine with an
+    accelerator it never reaches for the chip this process may hold."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count={n_devices}").strip()
     env["PYTHONPATH"] = os.path.join(REPO, "src")

@@ -118,3 +118,29 @@ def test_formats_are_distinct_but_sizes_equal():
     b = np.asarray(ref.encode(jnp.asarray(mag), 30, "register_block"))
     assert a.shape == b.shape
     assert not np.array_equal(a, b)  # different interleave, same size
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas_interpret"])
+@pytest.mark.parametrize("design", DESIGNS)
+def test_offset_decode_one_program_for_every_offset(design, backend):
+    """A 4-plane slice at any offset decodes to exactly that slice's bits of
+    the magnitude, and every offset reuses one compiled program (the offset
+    is a traced shift)."""
+    rng = np.random.default_rng(11)
+    n, total, rows = 5000, 24, 4
+    mags = rng.integers(0, 2 ** total, (2, n)).astype(np.uint32)
+    planes = jnp.stack([ref.encode(jnp.asarray(m), total, design)
+                        for m in mags])
+    ops._decode_shifted_batch.clear_cache()
+    for offset in range(0, total - rows + 1, rows):
+        got = ops.decode_bitplanes_offset_batch(
+            planes[:, offset:offset + rows], total, n, offset, design,
+            backend=backend)
+        lo = total - offset - rows
+        want = mags & np.uint32(((1 << rows) - 1) << lo)
+        assert np.array_equal(np.asarray(got), want), offset
+    assert ops._decode_shifted_batch._cache_size() == 1
+    with pytest.raises(ValueError, match="overrun"):
+        ops.decode_bitplanes_offset_batch(planes[:, :rows], total, n,
+                                          total - rows + 1, design,
+                                          backend=backend)

@@ -15,7 +15,8 @@ def test_sharded_train_step_runs(subproc):
         from repro.optim import adamw
         from repro.train import step as steps
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((4, 2), ("data", "model"))
         cfg = smoke_config("deepseek-67b")
         shape = ShapeConfig("t", 32, 8, "train")
         with shd.use_mesh(mesh):
@@ -40,7 +41,8 @@ def test_compressed_allreduce_saves_wire(subproc):
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.distributed.grad_compress import make_compressed_allreduce
         from repro.launch.hlo_analysis import HloAnalysis
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((8,), ("data",))
         n = 1 << 20
 
         def plain(x):
@@ -84,7 +86,8 @@ def test_elastic_restore_across_meshes(subproc):
         cfg = smoke_config("qwen2-7b")
         model = Model(cfg)
         # save under an 8-device (4,2) mesh
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import auto_mesh
+        mesh_a = auto_mesh((4, 2), ("data", "model"))
         with shd.use_mesh(mesh_a):
             sh_a = jax.tree.map(lambda s: NamedSharding(mesh_a, s),
                                 model.partition_specs())
@@ -118,7 +121,8 @@ def test_mini_dryrun_all_step_kinds(subproc):
         from repro.optim import adamw
         from repro.train import step as steps
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((2, 2, 2), ("pod", "data", "model"))
         for arch in ["jamba-v0.1-52b", "deepseek-v2-236b"]:
             cfg = smoke_config(arch)
             for kind, b, s in [("train", 8, 32), ("prefill", 4, 64),

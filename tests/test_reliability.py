@@ -350,6 +350,22 @@ def test_caching_backend_prefetch_worker_survives_inner_failure():
     cb.close()
 
 
+def test_caching_backend_counts_swallowed_fetch_errors():
+    """The prefetch worker and the service's cache warming swallow inner
+    read failures; ``fetch_errors`` still counts every one of them."""
+    inner = _BlockingFlaky()
+    inner.release.set()  # don't block; first read still raises
+    cb = bk.CachingBackend(inner, workers=1)
+    cb.prefetch("k", 0, 8)  # raises inside the worker, swallowed there
+    deadline = time.time() + 5.0
+    while cb.stats.fetch_errors == 0 and time.time() < deadline:
+        time.sleep(0.01)
+    assert cb.stats.snapshot()["fetch_errors"] == 1
+    assert cb.read("k", 0, 8) == b"d" * 8  # later reads succeed, no count
+    assert cb.stats.snapshot()["fetch_errors"] == 1
+    cb.close()
+
+
 def test_local_file_backend_truncated_read_is_typed(tmp_path):
     p = tmp_path / "seg"
     p.write_bytes(b"0123456789")

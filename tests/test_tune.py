@@ -348,6 +348,41 @@ def test_tune_survives_probe_failures(tmp_path, monkeypatch):
     assert tn.tune((512,), levels=1).cache_hit
 
 
+class _LoweringFailsModel(_FakeModel):
+    """A cost model whose lowering of every ``locality`` candidate fails,
+    as the chip's compiler refuses a kernel."""
+
+    def score(self, cfg):
+        if cfg.design == "locality":
+            raise NotImplementedError("lowering refused: locality kernel")
+        return super().score(cfg)
+
+
+def test_tune_reports_candidate_lowering_failures(tmp_path, monkeypatch):
+    """A candidate that fails to lower is reported — in the result, in the
+    counters and in the cache entry — never silently dropped."""
+    _isolate(tmp_path, monkeypatch)
+    _patch_tuner(monkeypatch, lambda x, cfg, levels, repeats=2: 1.0)
+    monkeypatch.setattr(tsearch, "CostModel", _LoweringFailsModel)
+    tsearch.STATS.reset()
+    r = tn.tune((1024,), levels=2, probes=2)
+    n_loc = sum(1 for c in tsearch.candidate_space(DEFAULT_CONFIG, "jnp")
+                if c.design == "locality")
+    assert n_loc > 0 and len(r.failures) == n_loc
+    assert all(f.stage == "score" and f.config.design == "locality"
+               and "lowering refused" in f.error for f in r.failures)
+    assert all(c.design != "locality" for c, _ in r.scores)
+    snap = tsearch.STATS.snapshot()
+    assert snap["candidates_failed"] == n_loc
+    assert snap["candidates_scored"] == len(r.scores)
+    p = tcache._path(tcache.cache_root(),
+                     tcache.backend_fingerprint("auto", 1),
+                     tcache.problem_key((1024,), "float32", 2))
+    meta = json.loads(p.read_text())["meta"]
+    assert len(meta["failures"]) == n_loc
+    assert meta["failures"][0]["config"]["design"] == "locality"
+
+
 # -------------------------------------------------------------- cost model --
 
 def test_cost_model_real_program():
@@ -422,27 +457,46 @@ def test_tune_probes_read_depth_through_pipeline(tmp_path, monkeypatch):
 def test_platform_peaks_calibrated_from_roofline_artifact(tmp_path,
                                                           monkeypatch):
     """tune.cost reads the machine's roofline.json 'calibrated' section when
-    present (env-pointed artifact), and falls back to NOMINAL_PEAKS on any
-    platform mismatch, corruption, or unusable rates."""
+    present (env-pointed artifact), and falls back to the nominal PEAKS row
+    on any device-kind mismatch, corruption, or unusable rates."""
     from repro.tune import cost as tc
 
     art = tmp_path / "roofline.json"
     art.write_text(json.dumps({"calibrated": {
-        "platform": "cpu", "scale": 2.0,
+        "device_kind": "cpu", "scale": 2.0,
         "flops": 5e10, "hbm_bw": 1.5e10, "link_bw": 5e9}}))
     monkeypatch.setenv(tc.ROOFLINE_ARTIFACT_ENV, str(art))
-    p = tc.platform_peaks("cpu")
+    p = tc.device_peaks("cpu")
     assert (p.flops, p.hbm_bw, p.link_bw) == (5e10, 1.5e10, 5e9)
-    # another platform's artifact must not apply
-    assert tc.platform_peaks("gpu") == tc.NOMINAL_PEAKS["gpu"]
+    # another device kind's artifact must not apply
+    assert tc.device_peaks(tc.V5E) == tc.PEAKS[tc.V5E]
     # corrupt artifact -> nominal, never an exception
     art.write_text("{not json")
-    assert tc.platform_peaks("cpu") == tc.NOMINAL_PEAKS["cpu"]
+    assert tc.device_peaks("cpu") == tc.PEAKS["cpu"]
     # zero/non-finite rates are unusable -> nominal
     art.write_text(json.dumps({"calibrated": {
-        "platform": "cpu", "flops": 0.0, "hbm_bw": 1e9, "link_bw": 1e9}}))
-    assert tc.platform_peaks("cpu") == tc.NOMINAL_PEAKS["cpu"]
+        "device_kind": "cpu", "flops": 0.0, "hbm_bw": 1e9, "link_bw": 1e9}}))
+    assert tc.device_peaks("cpu") == tc.PEAKS["cpu"]
     # absent artifact -> nominal
     monkeypatch.delenv(tc.ROOFLINE_ARTIFACT_ENV)
     monkeypatch.chdir(tmp_path)
-    assert tc.platform_peaks("cpu") == tc.NOMINAL_PEAKS["cpu"]
+    assert tc.device_peaks("cpu") == tc.PEAKS["cpu"]
+
+
+def test_unknown_device_kind_raises(tmp_path, monkeypatch):
+    """The peaks table is keyed by device_kind: a device it does not know
+    is an error, not a silent CPU (or any other) default — even when a
+    calibration artifact for that kind is present."""
+    from repro.tune import cost as tc
+
+    art = tmp_path / "roofline.json"
+    art.write_text(json.dumps({"calibrated": {
+        "device_kind": "TPU v9 imaginary", "scale": 1.0,
+        "flops": 1e12, "hbm_bw": 1e11, "link_bw": 1e10}}))
+    monkeypatch.setenv(tc.ROOFLINE_ARTIFACT_ENV, str(art))
+    with pytest.raises(ValueError, match="TPU v9 imaginary"):
+        tc.device_peaks("TPU v9 imaginary")
+    with pytest.raises(ValueError):
+        tc.nominal_peaks("gpu")
+    assert set(tc.PEAKS) == {tc.V5E, "cpu"}
+    assert tc.PEAKS[tc.V5E] == tc.Peaks(197e12, 819e9, 200e9)
