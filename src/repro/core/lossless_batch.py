@@ -352,6 +352,12 @@ def encode_groups(blobs: Sequence[jax.Array],
     no padding work — and runs through one vmapped stats/pack/scan
     invocation per codec; ALL buckets' stats respectively payloads are
     materialized by the same single ``host_sync``."""
+    with obs_trace.span("codec.encode"):
+        return _encode_groups(blobs, cfg)
+
+
+def _encode_groups(blobs: Sequence[jax.Array], cfg: ll.HybridConfig
+                   ) -> List[ll.Segment]:
     if not blobs:
         return []
     sizes = [int(np.prod(b.shape, dtype=np.int64)) for b in blobs]
@@ -402,6 +408,12 @@ def encode_groups_stacked(stacks: Sequence[jax.Array],
     Returns one ``lossless.Segment`` per row, flattened row-major across
     ``stacks`` — bit-identical to calling ``encode_groups`` on the individual
     rows, with the engine's same two host syncs."""
+    with obs_trace.span("codec.encode"):
+        return _encode_groups_stacked(stacks, cfg)
+
+
+def _encode_groups_stacked(stacks: Sequence[jax.Array], cfg: ll.HybridConfig
+                           ) -> List[ll.Segment]:
     sizes: List[int] = []
     for st in stacks:
         s = int(st.shape[1])
@@ -464,119 +476,131 @@ def _encode_buckets(stacked: Dict[tuple, jax.Array],
     accelerators stage 1 stays the device kernel — only tiny stats cross
     the PCIe link.  Both paths keep the engine's two-syncs-per-call
     contract and are byte-identical (``hist_batches`` counts stats batch
-    computations on either path)."""
+    computations on either path).
+
+    Each host stage runs under a span of its own, in order: ``codec.stats``
+    (stats and sync #1), ``codec.select``, ``codec.pack`` (the pack/scan
+    dispatches), ``codec.payload`` (sync #2) and ``codec.segments``, so a
+    profiler trace can say which stage the device waits on.  They nest in
+    the entry point's ``codec.encode``, which also holds the stacking
+    before them and the release of the call's buffers as it returns."""
     # stage 1: all histograms + run counts, one batch per bucket, ONE sync
     rows_host: Optional[Dict[tuple, np.ndarray]] = None
-    if jax.default_backend() == "cpu":
-        rows_host = host_sync(stacked, label="codec.stats")
-        stats_host = {}
-        for k, rows in rows_host.items():
-            STATS.add(hist_batches=1)
-            stats_host[k] = _group_stats_host(rows)
-    else:
-        stats_dev = {}
-        for k, st in stacked.items():
-            STATS.add(hist_batches=1)
-            stats_dev[k] = _group_stats_batch(st)
-        stats_host = host_sync(stats_dev, label="codec.stats")
+    with obs_trace.span("codec.stats"):
+        if jax.default_backend() == "cpu":
+            rows_host = host_sync(stacked, label="codec.stats")
+            stats_host = {}
+            for k, rows in rows_host.items():
+                STATS.add(hist_batches=1)
+                stats_host[k] = _group_stats_host(rows)
+        else:
+            stats_dev = {}
+            for k, st in stacked.items():
+                STATS.add(hist_batches=1)
+                stats_dev[k] = _group_stats_batch(st)
+            stats_host = host_sync(stats_dev, label="codec.stats")
 
-    # stage 2: Algorithm-2 selection + codebooks (host, trivial)
+    # stage 2: Algorithm-2 selection + codebooks (host)
     methods: Dict[int, str] = {}
     books: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
-    for k, idxs in buckets.items():
-        s = k[0]
-        hists, nruns = stats_host[k]
-        for j, i in enumerate(idxs):
-            m, book = _select(s, hists[j].astype(np.int64), int(nruns[j]),
-                              cfg)
-            methods[i] = m
-            if book is not None:
-                books[i] = book
+    with obs_trace.span("codec.select"):
+        for k, idxs in buckets.items():
+            s = k[0]
+            hists, nruns = stats_host[k]
+            for j, i in enumerate(idxs):
+                m, book = _select(s, hists[j].astype(np.int64),
+                                  int(nruns[j]), cfg)
+                methods[i] = m
+                if book is not None:
+                    books[i] = book
 
     # stage 3: dispatch one pack/scan per (bucket, codec), ONE payload sync
     pend: List[Tuple[str, int, List[int], object]] = []
-    for k, idxs in buckets.items():
-        s = k[0]
-        st = stacked[k]
-        pos = {i: j for j, i in enumerate(idxs)}
-        h = [i for i in idxs if methods[i] == "huffman"]
-        r = [i for i in idxs if methods[i] == "rle"]
-        d = [i for i in idxs if methods[i] == "dc"]
+    with obs_trace.span("codec.pack"):
+        for k, idxs in buckets.items():
+            s = k[0]
+            st = stacked[k]
+            pos = {i: j for j, i in enumerate(idxs)}
+            h = [i for i in idxs if methods[i] == "huffman"]
+            r = [i for i in idxs if methods[i] == "rle"]
+            d = [i for i in idxs if methods[i] == "dc"]
 
-        def rows_for(sel_idx: List[int]) -> jax.Array:
-            # codec row selection: host take + upload when the rows are
-            # already host-side (CPU stats path), device gather otherwise
-            sel = [pos[i] for i in sel_idx]
-            if rows_host is not None:
-                return jax.device_put(rows_host[k][np.asarray(sel)])
-            return st[jnp.asarray(sel, jnp.int32)]
+            def rows_for(sel_idx: List[int]) -> jax.Array:
+                # codec row selection: host take + upload when the rows are
+                # already host-side (CPU stats path), device gather otherwise
+                sel = [pos[i] for i in sel_idx]
+                if rows_host is not None:
+                    return jax.device_put(rows_host[k][np.asarray(sel)])
+                return st[jnp.asarray(sel, jnp.int32)]
 
-        if h:
-            lens_tab = jax.device_put(
-                np.stack([books[i][0] for i in h]).astype(np.uint32))
-            codes_tab = jax.device_put(np.stack([books[i][1] for i in h]))
-            STATS.add(huffman_pack_batches=1)
-            pend.append(("huffman", s, h,
-                         _huffman_pack_batch(rows_for(h), lens_tab,
-                                             codes_tab)))
-        if r:
-            STATS.add(rle_scan_batches=1)
-            pend.append(("rle", s, r, _rle_scan_batch(rows_for(r))))
-        if d:
-            if rows_host is not None:
-                # dc payloads are the raw rows — already on host, no
-                # device round-trip; .copy() detaches from the big stack
-                for i in d:
-                    segs[i] = ll.Segment("dc", s,
-                                         {"raw": rows_host[k][pos[i]].copy()},
-                                         {"n_syms": s})
+            if h:
+                lens_tab = jax.device_put(
+                    np.stack([books[i][0] for i in h]).astype(np.uint32))
+                codes_tab = jax.device_put(np.stack([books[i][1] for i in h]))
+                STATS.add(huffman_pack_batches=1)
+                pend.append(("huffman", s, h,
+                             _huffman_pack_batch(rows_for(h), lens_tab,
+                                                 codes_tab)))
+            if r:
+                STATS.add(rle_scan_batches=1)
+                pend.append(("rle", s, r, _rle_scan_batch(rows_for(r))))
+            if d:
+                if rows_host is not None:
+                    # dc payloads are the raw rows — already on host, no
+                    # device round-trip; .copy() detaches from the big stack
+                    for i in d:
+                        segs[i] = ll.Segment(
+                            "dc", s, {"raw": rows_host[k][pos[i]].copy()},
+                            {"n_syms": s})
+                else:
+                    pend.append(("dc", s, d, rows_for(d)))
+    with obs_trace.span("codec.payload"):
+        mats = host_sync([p[3] for p in pend], label="codec.payload")
+
+    with obs_trace.span("codec.segments"):
+        for (kind, s, idxs, _), mat in zip(pend, mats):
+            if kind == "huffman":
+                words_b, bits_b, offs_b = mat
+                for j, i in enumerate(idxs):
+                    total_bits = int(bits_b[j])
+                    n_words = (total_bits + 31) // 32 + 1
+                    segs[i] = ll.Segment(
+                        "huffman", s,
+                        payload={"words": words_b[j, :n_words].copy(),
+                                 "chunk_offs": np.array(offs_b[j],
+                                                        dtype=np.uint32),
+                                 "lengths": books[i][0]},
+                        meta={"n_syms": s, "total_bits": total_bits})
+            elif kind == "rle":
+                vals_b, lens_b, nruns_b = mat
+                for j, i in enumerate(idxs):
+                    r = int(nruns_b[j])
+                    segs[i] = ll.Segment(
+                        "rle", s,
+                        payload={"values": vals_b[j, :r].copy(),
+                                 "lengths": lens_b[j, :r].astype(np.uint16)},
+                        meta={"n_syms": s})
             else:
-                pend.append(("dc", s, d, rows_for(d)))
-    mats = host_sync([p[3] for p in pend], label="codec.payload")
+                for j, i in enumerate(idxs):
+                    segs[i] = ll.Segment("dc", s, {"raw": mat[j].copy()},
+                                         {"n_syms": s})
 
-    for (kind, s, idxs, _), mat in zip(pend, mats):
-        if kind == "huffman":
-            words_b, bits_b, offs_b = mat
-            for j, i in enumerate(idxs):
-                total_bits = int(bits_b[j])
-                n_words = (total_bits + 31) // 32 + 1
-                segs[i] = ll.Segment(
-                    "huffman", s,
-                    payload={"words": words_b[j, :n_words].copy(),
-                             "chunk_offs": np.array(offs_b[j],
-                                                    dtype=np.uint32),
-                             "lengths": books[i][0]},
-                    meta={"n_syms": s, "total_bits": total_bits})
-        elif kind == "rle":
-            vals_b, lens_b, nruns_b = mat
-            for j, i in enumerate(idxs):
-                r = int(nruns_b[j])
-                segs[i] = ll.Segment(
-                    "rle", s,
-                    payload={"values": vals_b[j, :r].copy(),
-                             "lengths": lens_b[j, :r].astype(np.uint16)},
-                    meta={"n_syms": s})
-        else:
-            for j, i in enumerate(idxs):
-                segs[i] = ll.Segment("dc", s, {"raw": mat[j].copy()},
-                                     {"n_syms": s})
-
-    # per-codec byte accounting (obs.metrics): bytes_in is the raw blob
-    # size, bytes_out the stored payload — compression_ratio per codec is
-    # bytes_in / bytes_out of the same series
-    per_codec: Dict[str, List[int]] = {}
-    for idxs in buckets.values():
-        for i in idxs:
-            seg = segs[i]
-            acc = per_codec.setdefault(seg.method, [0, 0, 0])
-            acc[0] += 1
-            acc[1] += seg.n_bytes
-            acc[2] += sum(a.nbytes for a in seg.payload.values())
-    m = obs_metrics.get()
-    for method, (n, bin_, bout) in per_codec.items():
-        m.inc("codec.groups", n, codec=method)
-        m.inc("codec.bytes_in", bin_, codec=method)
-        m.inc("codec.bytes_out", bout, codec=method)
+        # per-codec byte accounting (obs.metrics): bytes_in is the raw blob
+        # size, bytes_out the stored payload — compression_ratio per codec
+        # is bytes_in / bytes_out of the same series
+        per_codec: Dict[str, List[int]] = {}
+        for idxs in buckets.values():
+            for i in idxs:
+                seg = segs[i]
+                acc = per_codec.setdefault(seg.method, [0, 0, 0])
+                acc[0] += 1
+                acc[1] += seg.n_bytes
+                acc[2] += sum(a.nbytes for a in seg.payload.values())
+        m = obs_metrics.get()
+        for method, (n, bin_, bout) in per_codec.items():
+            m.inc("codec.groups", n, codec=method)
+            m.inc("codec.bytes_in", bin_, codec=method)
+            m.inc("codec.bytes_out", bout, codec=method)
 
 
 # ------------------------------------------------------------------- decode --

@@ -359,11 +359,9 @@ class ChunkedRefactorPipeline:
         disp0 = rff.STATS.dispatches
         blobs: List[Optional[bytes]] = [None] * len(slices)
         # async-drain attribution (pipelined path): chunks per device at
-        # each drain, drain count, and host-blocked seconds during which a
-        # device queue sat empty
+        # each drain, and the drain count
         depth_at_drain: collections.Counter = collections.Counter()
         n_drains = [0]
-        idle_at_drain = [0.0]
 
         if not self.pipelined:
             for ci, sl in enumerate(slices):
@@ -461,15 +459,8 @@ class ChunkedRefactorPipeline:
                 refill_nowait()
                 depth_at_drain.update(
                     self.sharded.shard_for(cj) for cj, _ in batch)
-                live = {self.sharded.shard_for(cj) for cj, _ in inflight}
                 n_drains[0] += 1
-                t0 = time.perf_counter()
                 outs = self._finish_many([p for _, p in batch])
-                # idle-at-drain: devices with an empty queue during this
-                # host-blocking finish had nothing to execute — attributable
-                # scheduler slack (gauged as write.idle_at_drain_s)
-                idle_at_drain[0] += (time.perf_counter() - t0) * sum(
-                    1 for d in range(self.n_shards) if d not in live)
                 for (cj, _), refd in zip(batch, outs):
                     out_q.put((cj, refd))
 
@@ -508,7 +499,6 @@ class ChunkedRefactorPipeline:
                 for d in range(self.n_shards):
                     m.gauge(f"write.inflight_depth.d{d}",
                             depth_at_drain[d] / n_drains[0])
-                m.gauge("write.idle_at_drain_s", idle_at_drain[0])
         return [b for b in blobs if b is not None]
 
 

@@ -177,35 +177,44 @@ def fused_encode_plan(shape: Tuple[int, ...], levels: int, design: str,
         for gi in range(len(group_planes)):
             entries.append(_StackEntry("group", gi, tuple(idxs), w))
 
+    # each stage under a ``jax.named_scope`` (decompose, align, stack,
+    # bitplane, rows), so a device trace can split the program's time
     def _run(x):
         x = x.astype(jnp.float32)
-        pieces = dc.decompose(x, levels)
+        with jax.named_scope("decompose"):
+            pieces = dc.decompose(x, levels)
         exps = []
         mags: List[jax.Array] = [None] * len(pieces)
         signs: List[jax.Array] = [None] * len(pieces)
-        for pi, piece in enumerate(pieces):
-            mag, sign, e = al.align_encode(piece, mag_bits)
-            exps.append(e)
-            mags[pi], signs[pi] = mag, sign
+        with jax.named_scope("align"):
+            for pi, piece in enumerate(pieces):
+                mag, sign, e = al.align_encode(piece, mag_bits)
+                exps.append(e)
+                mags[pi], signs[pi] = mag, sign
         outs = [jnp.stack(exps)]
         if size:
             outs.append(jnp.max(jnp.abs(x)))
             outs.append(jnp.max(x) - jnp.min(x))
         for w, idxs in buckets.items():
             n_pad = 32 * w
-            mstack = jnp.stack([jnp.pad(mags[i], (0, n_pad - piece_ns[i]))
-                                for i in idxs])
-            sstack = jnp.stack([jnp.pad(signs[i], (0, n_pad - piece_ns[i]))
-                                for i in idxs])
-            planes = kops.encode_bitplanes_batch(
-                mstack, mag_bits, design, backend, tiles_per_block, unroll)
-            sign_planes = kops.encode_bitplanes_batch(
-                sstack, 1, design, backend, tiles_per_block, unroll)
-            outs.append(_bytes_rows(sign_planes))
-            row = 0
-            for g in group_planes:
-                outs.append(_bytes_rows(planes[:, row:row + g, :]))
-                row += g
+            with jax.named_scope("stack"):
+                mstack = jnp.stack([jnp.pad(mags[i], (0, n_pad - piece_ns[i]))
+                                    for i in idxs])
+                sstack = jnp.stack([jnp.pad(signs[i],
+                                            (0, n_pad - piece_ns[i]))
+                                    for i in idxs])
+            with jax.named_scope("bitplane"):
+                planes = kops.encode_bitplanes_batch(
+                    mstack, mag_bits, design, backend, tiles_per_block,
+                    unroll)
+                sign_planes = kops.encode_bitplanes_batch(
+                    sstack, 1, design, backend, tiles_per_block, unroll)
+            with jax.named_scope("rows"):
+                outs.append(_bytes_rows(sign_planes))
+                row = 0
+                for g in group_planes:
+                    outs.append(_bytes_rows(planes[:, row:row + g, :]))
+                    row += g
         return tuple(outs)
 
     # run_donated aliases the input buffer into the program's workspace
@@ -305,9 +314,11 @@ def finish_encode(p: PendingChunk, _scalars=None) -> rf.Refactored:
     sync; values must be exactly ``host_sync((p.exps, p.amax, p.rng))``."""
     STATS.add(finishes=1)
     with obs_trace.span("encode.finish", name=p.name):
-        scalars = (lb.host_sync((p.exps, p.amax, p.rng),
-                                label="encode.scalars")
-                   if _scalars is None else _scalars)
+        scalars = _scalars
+        if scalars is None:
+            with obs_trace.span("encode.scalars"):
+                scalars = lb.host_sync((p.exps, p.amax, p.rng),
+                                       label="encode.scalars")
         segs_flat = lb.encode_groups_stacked(p.stacks, p.hybrid)
         return _assemble(p, scalars, segs_flat)
 
@@ -336,8 +347,10 @@ def finish_encode_many(pendings: Sequence[PendingChunk], _scalars=None
     if not pendings:
         return []
     if _scalars is None:
-        _scalars = lb.host_sync([(p.exps, p.amax, p.rng) for p in pendings],
-                                label="encode.scalars")
+        with obs_trace.span("encode.scalars"):
+            _scalars = lb.host_sync(
+                [(p.exps, p.amax, p.rng) for p in pendings],
+                label="encode.scalars")
     STATS.add(finishes=len(pendings))
     out: List[Optional[rf.Refactored]] = [None] * len(pendings)
     by_cfg = lb.batch_jobs(pendings, lambda p: (

@@ -212,6 +212,7 @@ def encode_pallas(mag: jax.Array, num_planes: int, design: str = "register_block
     out = pl.pallas_call(
         kern,
         grid=(g,),
+        name=f"bitplane_encode_{design}",
         in_specs=[in_spec],
         out_specs=pl.BlockSpec((num_planes, wpb), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((num_planes, W), jnp.uint32),
@@ -245,6 +246,7 @@ def decode_pallas(planes: jax.Array, num_planes_total: int, n: int,
         out2 = pl.pallas_call(
             kern,
             grid=(g,),
+            name="bitplane_decode_register_block",
             in_specs=[pl.BlockSpec((P, wpb), lambda i: (0, i))],
             out_specs=pl.BlockSpec((TILE_SUB * tiles_per_block, TILE_LANE),
                                    lambda i: (i, 0)),
@@ -260,6 +262,7 @@ def decode_pallas(planes: jax.Array, num_planes_total: int, n: int,
         out2 = pl.pallas_call(
             kern,
             grid=(g,),
+            name="bitplane_decode_locality",
             in_specs=[pl.BlockSpec((P, wpb), lambda i: (0, i))],
             out_specs=pl.BlockSpec((TILE_LANE * tiles_per_block, 32),
                                    lambda i: (i, 0)),

@@ -126,6 +126,12 @@ class DatasetWriter:
         # REWRITE — the new generation replaces it when finalize() commits
         if name in self._written:
             raise ValueError(f"variable {name!r} already written")
+        # the whole write runs under ``store.write``, so a profiler trace
+        # puts every second of it down to a program span
+        with obs_trace.span("store.write", var=name):
+            return self._write(name, x)
+
+    def _write(self, name: str, x: np.ndarray) -> lo.VariableEntry:
         x = np.asarray(x, dtype=np.float32)
         shape = tuple(int(s) for s in x.shape)
         # NB: ascontiguousarray promotes 0-d to 1-d, hence shape captured first
@@ -154,43 +160,49 @@ class DatasetWriter:
             mesh=self.mesh, config=self.config,
             use_tune_cache=self.use_tune_cache)
         try:
-            with obs_trace.span("store.write", var=name):
-                pipe.refactor(flat, name=name)
+            pipe.refactor(flat, name=name)
         finally:
-            seg_writer.close()
+            with obs_trace.span("store.segment_close"):
+                seg_writer.close()
 
-        # manifest fields record the EFFECTIVE knobs the pipeline resolved
-        # (legacy kwargs > config= > tune cache > defaults), and ``plan``
-        # captures the full config so readers replay the tuned plan
-        entry = lo.VariableEntry(
-            name=name, shape=shape, levels=levels,
-            design=pipe.design,
-            mag_bits=pipe.config.resolved_mag_bits(),
-            group_size=pipe.hybrid.group_size, chunk_elems=self.chunk_elems,
-            segment_file=seg_key,
-            amax=float(np.abs(x).max()) if x.size else 0.0,
-            range=float(x.max() - x.min()) if x.size else 0.0,
-            chunks=chunks,
-            shards=(pipe.chunk_shards(len(chunks))
-                    if self.mesh is not None else None),
-            plan=pipe.config.to_json())
+        # the manifest entry: three host passes over ``x`` (amax, range),
+        # and the compression accounting
+        with obs_trace.span("store.entry"):
+            # manifest fields record the EFFECTIVE knobs the pipeline
+            # resolved (legacy kwargs > config= > tune cache > defaults), and
+            # ``plan`` captures the full config so readers replay the plan
+            entry = lo.VariableEntry(
+                name=name, shape=shape, levels=levels,
+                design=pipe.design,
+                mag_bits=pipe.config.resolved_mag_bits(),
+                group_size=pipe.hybrid.group_size,
+                chunk_elems=self.chunk_elems,
+                segment_file=seg_key,
+                amax=float(np.abs(x).max()) if x.size else 0.0,
+                range=float(x.max() - x.min()) if x.size else 0.0,
+                chunks=chunks,
+                shards=(pipe.chunk_shards(len(chunks))
+                        if self.mesh is not None else None),
+                plan=pipe.config.to_json())
+            # compression accounting: raw input bytes vs bytes landed in
+            # the segment file (payloads + per-group headers).  ratio >= 1
+            # is a win.
+            raw, stored = int(flat.nbytes), int(entry.stored_bytes)
+            m = obs_metrics.REGISTRY.get()
+            m.inc("store.bytes_raw", raw, var=name)
+            m.inc("store.bytes_stored", stored, var=name)
+            if stored:
+                m.gauge("store.compression_ratio", raw / stored, var=name)
+            if stored > raw:
+                logger.warning(
+                    "store write of %r EXPANDED the data: stored %d bytes "
+                    "for %d raw bytes (ratio %.3f < 1.0) — the lossless "
+                    "stage is losing to the bitplane/group framing on this "
+                    "input; see docs/observability.md#compression-accounting",
+                    name,
+                    stored, raw, raw / max(stored, 1))
         self.manifest.variables[name] = entry
         self._written.add(name)
-        # compression accounting: raw input bytes vs bytes landed in the
-        # segment file (payloads + per-group headers).  ratio >= 1 is a win.
-        raw, stored = int(flat.nbytes), int(entry.stored_bytes)
-        m = obs_metrics.REGISTRY.get()
-        m.inc("store.bytes_raw", raw, var=name)
-        m.inc("store.bytes_stored", stored, var=name)
-        if stored:
-            m.gauge("store.compression_ratio", raw / stored, var=name)
-        if stored > raw:
-            logger.warning(
-                "store write of %r EXPANDED the data: stored %d bytes for "
-                "%d raw bytes (ratio %.3f < 1.0) — the lossless stage is "
-                "losing to the bitplane/group framing on this input; see "
-                "docs/observability.md#compression-accounting", name,
-                stored, raw, raw / max(stored, 1))
         return entry
 
     # ----------------------------------------------------------- finalize --
@@ -199,9 +211,10 @@ class DatasetWriter:
             return os.path.join(self.root, lo.MANIFEST_NAME)
         path = os.path.join(self.root, lo.MANIFEST_NAME)
         tmp = path + ".tmp"
-        with open(tmp, "w") as f:
-            json.dump(self.manifest.to_json(), f)
-        os.replace(tmp, path)
+        with obs_trace.span("store.finalize"):
+            with open(tmp, "w") as f:
+                json.dump(self.manifest.to_json(), f)
+            os.replace(tmp, path)
         self._finalized = True
         return path
 

@@ -215,10 +215,67 @@ def test_traced_write_host_sync_budget_matches_chrome_trace():
     snap = m.snapshot()
     assert snap["gauges"]["write.syncs_per_chunk"] == 3 * drains / n
     assert snap["gauges"]["write.dispatches_per_chunk"] == 1.0
-    # async-drain attribution gauges: mean in-flight depth per device at
-    # drain time equals the full window; idle accounting is present
+    # async-drain attribution gauge: mean in-flight depth per device at
+    # drain time equals the full window
     assert snap["gauges"]["write.inflight_depth.d0"] == 2.0
-    assert snap["gauges"]["write.idle_at_drain_s"] >= 0.0
+
+
+CODEC_SPANS = ("codec.stats", "codec.select", "codec.pack", "codec.payload",
+               "codec.segments")
+
+
+def test_traced_write_codec_stages_nest_under_the_drain():
+    """Each drain of a pipelined write opens each codec host stage once, in
+    order, inside one ``codec.encode`` under ``encode.finish_many``; the
+    scalar gather has its own ``encode.scalars`` span; every host sync sits
+    in the span of its label's name, so a trace gap under a sync names the
+    stage."""
+    n, drains = 4, 2
+    _, _, tr, st, _ = _traced_write(n_chunks=n)
+    spans = tr.spans()
+    by_id = {s.span_id: s for s in spans}
+
+    def parents(name):
+        mine = [s for s in spans if s.name == name]
+        assert len(mine) == drains, name
+        return {by_id[s.parent_id].name for s in mine}
+
+    for name in CODEC_SPANS:
+        assert parents(name) == {"codec.encode"}, name
+    assert parents("codec.encode") == {"encode.finish_many"}
+    assert parents("encode.scalars") == {"sharded.finish_many"}
+    # per drain, the stages run in order and do not overlap
+    for enc in (s for s in spans if s.name == "codec.encode"):
+        kids = sorted((s for s in spans if s.parent_id == enc.span_id),
+                      key=lambda s: s.t0)
+        assert [s.name for s in kids] == list(CODEC_SPANS)
+        assert all(a.t1 <= b.t0 for a, b in zip(kids, kids[1:]))
+    syncs = tr.events(obs_trace.EV_HOST_SYNC)
+    assert len(syncs) == st.host_syncs == 3 * drains
+    assert all(span_.name == ev.attrs["label"] for span_, ev in syncs)
+
+
+def test_store_spans_open_once_per_write_and_finalize(tmp_path):
+    """``store.write`` holds the whole write (the pipeline, the segment
+    file's close and the manifest entry); ``store.finalize`` the manifest
+    commit."""
+    x = gaussian_field((2 * 4096,), slope=-2.0, seed=6)
+    with obs_trace.tracing() as tr:
+        with DatasetWriter(str(tmp_path / "s"), chunk_elems=4096) as w:
+            w.write("a", x)
+            w.write("b", x[::-1].copy())
+    spans = tr.spans()
+    by_id = {s.span_id: s for s in spans}
+    per = tr.summary()["spans"]
+    assert per["store.write"]["count"] == 2
+    assert per["store.entry"]["count"] == 2
+    assert per["store.segment_close"]["count"] == 2
+    assert per["store.finalize"]["count"] == 1
+    for s in spans:
+        if s.name in ("store.entry", "store.segment_close", "write.refactor"):
+            assert by_id[s.parent_id].name == "store.write", s.name
+    fin = next(s for s in spans if s.name == "store.finalize")
+    assert fin.parent_id is None
 
 
 def test_traced_read_adds_one_sync_per_chunk():

@@ -16,6 +16,7 @@ from repro.core import refactor as rf
 from repro.core import refactor_fused as rff
 from repro.core import retrieve as rt
 from repro.kernels import ops as kops
+from repro.tune import DEFAULT_CONFIG
 from repro.data.fields import gaussian_field
 
 RNG = np.random.default_rng(17)
@@ -171,6 +172,21 @@ def test_fused_is_default_and_plan_cache_reused():
     snap = rff.STATS.snapshot()
     assert snap["dispatches"] == 2          # fused is the default path
     assert snap["plan_builds"] == builds_first  # second chunk reuses the plan
+
+
+def test_fused_program_names_its_stages():
+    """Each stage of the fused program lowers under its ``jax.named_scope``,
+    so a device trace can split the program's time by stage."""
+    cfg = DEFAULT_CONFIG
+    mag_bits = cfg.resolved_mag_bits()
+    plan = rff.fused_encode_plan(
+        (4096,), 2, cfg.design, mag_bits,
+        tuple(rf._group_plane_split(mag_bits, cfg.group_size)), "jnp",
+        cfg.tiles_per_block, cfg.unroll)
+    text = plan.run.lower(jax.ShapeDtypeStruct((4096,), np.float32)
+                          ).as_text(debug_info=True)
+    for scope in ("decompose", "align", "stack", "bitplane", "rows"):
+        assert f"jit(_run)/{scope}/" in text, scope
 
 
 # ------------------------------------------------- pipeline dispatch-ahead

@@ -70,6 +70,7 @@ def test_encode_compiles_for_v5e(one_chip, design, tiles):
         lambda m: bp.encode_pallas(m, PLANES, design, tiles_per_block=tiles),
         x)
     assert "tpu_custom_call" in text
+    assert f"bitplane_encode_{design}" in text   # the kernel's trace name
 
 
 @pytest.mark.parametrize("design", DESIGNS)
@@ -80,6 +81,8 @@ def test_decode_compiles_for_v5e(one_chip, design, tiles):
         lambda q: bp.decode_pallas(q, PLANES, N, design,
                                    tiles_per_block=tiles), p)
     assert "tpu_custom_call" in text
+    kernel = "register_block" if design == "register_block" else "locality"
+    assert f"bitplane_decode_{kernel}" in text
 
 
 def test_batched_decode_compiles_for_v5e(one_chip):
