@@ -19,27 +19,33 @@ adds the 2*eps-corrupted detail rows to the (eps+c)-corrupted coarse rows ->
 from __future__ import annotations
 
 import functools
+import math
 from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 
 def _split_axis(x: jax.Array, axis: int) -> jax.Array:
-    """One 1-D decomposition step along ``axis``: returns [even | detail]."""
-    x = jnp.moveaxis(x, axis, -1)
-    n = x.shape[-1]
-    xe = x[..., 0::2]
-    xo = x[..., 1::2]
-    ne, no = xe.shape[-1], xo.shape[-1]
+    """One 1-D decomposition step along ``axis``: returns [even | detail].
+
+    Static (strided) slices only: a strided ``jnp`` index lowers to an
+    element gather, which costs far more than the transform's arithmetic
+    on an accelerator."""
+    n = x.shape[axis]
+    xe = lax.slice_in_dim(x, 0, n, 2, axis)
+    xo = lax.slice_in_dim(x, 1, n, 2, axis)
+    ne, no = xe.shape[axis], xo.shape[axis]
     # right neighbor of odd i is even i+1 (duplicate edge when absent)
-    right = xe[..., 1:no + 1] if ne > no else jnp.concatenate(
-        [xe[..., 1:], xe[..., -1:]], axis=-1)
-    pred = 0.5 * (xe[..., :no] + right)
+    right = lax.slice_in_dim(xe, 1, no + 1, axis=axis) if ne > no else \
+        jnp.concatenate([lax.slice_in_dim(xe, 1, ne, axis=axis),
+                         lax.slice_in_dim(xe, ne - 1, ne, axis=axis)],
+                        axis=axis)
+    pred = 0.5 * (lax.slice_in_dim(xe, 0, no, axis=axis) + right)
     detail = xo - pred
-    out = jnp.concatenate([xe, detail], axis=-1)
-    return jnp.moveaxis(out, -1, axis)
+    return jnp.concatenate([xe, detail], axis=axis)
 
 
 def _merge_axis(x: jax.Array, axis: int, n: int) -> jax.Array:
@@ -96,11 +102,26 @@ def decompose(x: jax.Array, levels: int) -> List[jax.Array]:
 
 
 def _extract_detail(full: jax.Array, cs: Tuple[int, ...]) -> jax.Array:
-    """All entries of ``full`` except the coarse corner, flattened (fixed order)."""
-    mask = np.ones(full.shape, dtype=bool)
-    mask[tuple(slice(0, c) for c in cs)] = False
-    idx = np.nonzero(mask.reshape(-1))[0]
-    return full.reshape(-1)[jnp.asarray(idx)]
+    """All entries of ``full`` except the coarse corner ``[:cs[0], :cs[1],
+    ...]``, flattened in row-major order, by static slices only."""
+    return _outside(full[None], cs).reshape(-1)
+
+
+def _outside(a: jax.Array, cs: Tuple[int, ...]) -> jax.Array:
+    """(B, *rest) -> (B, m): per leading row, the row-major entries of
+    ``rest`` outside the corner ``cs``.  Row-major order visits the corner's
+    leading slab first (each of its rows minus the corner in later axes),
+    then the rest of the axis whole."""
+    b, rest = a.shape[0], a.shape[1:]
+    m = math.prod(rest) - math.prod(cs)
+    if a.size == 0 or m == 0:
+        return jnp.zeros((b, m), a.dtype)
+    c = cs[0]
+    head = lax.slice_in_dim(a, 0, c, axis=1).reshape((b * c,) + rest[1:])
+    tail = lax.slice_in_dim(a, c, rest[0], axis=1)
+    parts = [p.reshape(b, -1) for p in (_outside(head, cs[1:]), tail)
+             if p.size]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
 
 
 def level_shapes(shape: Sequence[int], levels: int) -> List[Tuple[int, ...]]:

@@ -9,6 +9,7 @@ from hypothesis import given, settings, strategies as st
 import jax
 
 from repro.core import align as al
+from repro.core import decompose as dc
 from repro.core import lossless as ll
 from repro.core import lossless_batch as lb
 from repro.core import pipeline as pl
@@ -187,6 +188,21 @@ def test_fused_program_names_its_stages():
                           ).as_text(debug_info=True)
     for scope in ("decompose", "align", "stack", "bitplane", "rows"):
         assert f"jit(_run)/{scope}/" in text, scope
+
+
+@pytest.mark.parametrize("shape", [(1 << 20,), (40, 36, 33)])
+def test_fused_program_has_no_gather(shape):
+    """The decompose moves data by static slices only: a strided ``jnp``
+    index (``x[0::2]``) or an index-array take would lower to an element
+    gather, the slowest way to move the chunk on the chip."""
+    cfg = DEFAULT_CONFIG
+    mag_bits = cfg.resolved_mag_bits()
+    plan = rff.fused_encode_plan(
+        shape, dc.num_levels(shape), cfg.design, mag_bits,
+        tuple(rf._group_plane_split(mag_bits, cfg.group_size)), "jnp",
+        cfg.tiles_per_block, cfg.unroll)
+    text = plan.run.lower(jax.ShapeDtypeStruct(shape, np.float32)).as_text()
+    assert "gather" not in text and "scatter" not in text
 
 
 # ------------------------------------------------- pipeline dispatch-ahead

@@ -107,6 +107,7 @@ def test_fused_write_program_compiles_for_v5e(one_chip):
     x = jax.ShapeDtypeStruct((N,), jnp.float32, sharding=one_chip)
     text = plan.run.lower(x).compile().as_text()
     assert text.count("tpu_custom_call") >= 2   # magnitudes + signs
+    assert " gather(" not in text               # decompose: static slices
 
 
 def test_codec_stats_accelerator_branch_compiles_for_v5e(one_chip,
